@@ -8,13 +8,12 @@ entry is either a bare number (a lower-is-better CEILING: fail past
 `floor` metric is higher-is-better (throughput, utilization) and fails
 BELOW baseline / `factor`.  The default 2x factor is generous on
 purpose — shared CI runners are noisy; the gate exists to catch
-order-of-magnitude regressions like an accidental re-compile per request
-or a kernel utilization collapsing to zero, not 10% drift.  Only
+order-of-magnitude regressions like an accidental re-compile per request,
+not 10% drift.  Only
 load-robust metrics belong in the baseline: the deadline row's p99 rides
 on real-clock scheduler wakeups and swings 10x with CPU contention (its
-behavior is asserted by `--smoke` instead), while pow2 p99, flip_ms,
-failover_ms, and the kernels row's utilization_frac stay within ~2x
-under a fully loaded host.
+behavior is asserted by `--smoke` instead), while pow2 p99, flip_ms and
+failover_ms stay within ~2x under a fully loaded host.
 
 Measured rows/metrics with NO baseline entry are printed as
 "new row, no gate" / "new metric, no gate" — informational, never a

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.configs import registry
 from repro.core import easi
 from repro.data import synthetic
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.config import DRFrontendSpec
 from repro.train import optimizer as opt_mod
 from repro.train import train_step as ts_mod
@@ -60,6 +61,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=120)
     args = ap.parse_args()
+    use_compile_cache()
 
     base = registry.get_smoke("hubert_xlarge")
     print(f"== baseline (frontend_dim={base.frontend_dim} -> d_model direct) ==")

@@ -15,6 +15,9 @@ import jax.numpy as jnp
 from repro.core import easi
 from repro.data import mixtures
 from repro.dr import DRModel, EASIStage, RPStage
+from repro.launch.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 # 1. data: x = A s, 16 observed dims, 4 independent non-Gaussian sources
 x, a_true, _ = mixtures.mixture(n_samples=30000, m=16, n_src=4, seed=0,

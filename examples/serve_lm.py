@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.configs import registry
 from repro.dr import DRModel, EASIStage, RPStage
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_smoke_mesh
 from repro.models import api
 from repro.serve import (BucketPolicy, DRService, DeadlineScheduler, Elector,
@@ -55,6 +56,7 @@ def main():
     ap.add_argument("--tokens", type=int, default=16)
     ap.add_argument("--frame-dim", type=int, default=32)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = registry.get_smoke(args.arch)
     params = api.init_params(jax.random.PRNGKey(0), cfg)

@@ -25,6 +25,7 @@ from repro.configs import waveform_paper as wp
 from repro.core import pipeline
 from repro.core.execution import Execution
 from repro.data import waveform
+from repro.launch.compile_cache import use_compile_cache
 
 
 def run_row(name: str, cfg, seeds, xtr, ytr, xte, yte, fast=False, execution=None):
@@ -65,6 +66,7 @@ def main():
     ap.add_argument("--backend", choices=("xla", "pallas"), default="xla",
                     help="execution backend for every DR stage")
     args = ap.parse_args()
+    use_compile_cache()
     execution = Execution(backend=args.backend)
 
     (xtr, ytr), (xte, yte) = waveform.paper_split(seed=0)

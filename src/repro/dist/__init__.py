@@ -6,37 +6,10 @@
   compress — cross-pod gradient sync through the paper's own primitive:
              a ternary random-projection sketch, psum'd in sketch space
              and back-projected with error feedback.
-
-Importing this package also installs a `jax.shard_map` forwarding shim on
-older jax releases (< 0.5) where shard_map still lives under
-`jax.experimental.shard_map` and takes `check_rep` instead of `check_vma`,
-so call sites can be written against the modern spelling.
 """
 
 from __future__ import annotations
 
-import jax
-
-
-def _install_shard_map_compat() -> None:
-    if hasattr(jax, "shard_map"):
-        return
-
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None,
-                  check_rep=None, **kwargs):
-        if check_rep is None:
-            check_rep = True if check_vma is None else bool(check_vma)
-        return _legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=check_rep,
-                                 **kwargs)
-
-    jax.shard_map = shard_map
-
-
-_install_shard_map_compat()
-
-from repro.dist import compress, sharding  # noqa: E402
+from repro.dist import compress, sharding
 
 __all__ = ["compress", "sharding"]

@@ -36,48 +36,34 @@ PEAK_FLOPS = 197e12       # bf16 / chip
 HBM_BW = 819e9            # bytes/s / chip
 ICI_BW = 50e9             # bytes/s / link
 
-# Datasheet peaks per jax platform (per chip).  Platforms not listed here
-# (CPU CI hosts, mostly) get a MEASURED dense-matmul peak instead — a
-# utilization fraction judged against 197 TFLOP/s on a laptop core is
-# noise; judged against what that core's matmul actually sustains, it is
-# the same achieved-vs-peak statement the SNIPPETS.md MAX_TFLOPS tables
-# make (and the floor gate in benchmarks/baseline.json stays meaningful
-# across machines).
-PEAK_FLOPS_BY_PLATFORM = {"tpu": PEAK_FLOPS}
-
-_MEASURED_PEAK: Dict[str, float] = {}   # platform -> FLOP/s, probed once
-
-
-def measured_peak_flops(n: int = 512, reps: int = 5) -> float:
-    """Best-of-`reps` f32 dense-matmul throughput of the default device:
-    2n³ FLOPs over the fastest (n,n)@(n,n) wall time."""
-    import time
-
-    import jax
-    import jax.numpy as jnp
-
-    f = jax.jit(lambda a, b: a @ b)
-    a = jnp.full((n, n), 0.5, jnp.float32)
-    jax.block_until_ready(f(a, a))                    # compile outside timing
-    best = float("inf")
-    for _ in range(max(1, reps)):
-        t0 = time.perf_counter()
-        jax.block_until_ready(f(a, a))
-        best = min(best, time.perf_counter() - t0)
-    return 2.0 * n ** 3 / best
+# Published per-chip peaks, keyed by `jax.Device.device_kind`.  A kind that
+# is not listed has no peak: `device_peak_flops` raises rather than judging
+# a utilization against a guess or against a timed host matmul.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {
+        "flops_bf16": PEAK_FLOPS,
+        "hbm_bytes_per_s": HBM_BW,
+        "source": "Google Cloud TPU documentation, 'TPU v5e' system "
+                  "architecture page (per chip: 197 TFLOP/s bf16, "
+                  "819 GB/s HBM)",
+    },
+}
 
 
-def device_peak_flops(platform: Optional[str] = None) -> tuple:
-    """(peak FLOP/s, source) for `platform` (default: the jax backend):
-    the datasheet number where we have one, else a cached measured peak."""
-    import jax
+def device_peak_flops(device_kind: Optional[str] = None) -> tuple:
+    """(peak bf16 FLOP/s, source) of `device_kind` (default: the kind of
+    `jax.devices()[0]`).  Raises KeyError for a kind with no published
+    entry in `DEVICE_PEAKS`."""
+    if device_kind is None:
+        import jax
 
-    plat = platform if platform is not None else jax.default_backend()
-    if plat in PEAK_FLOPS_BY_PLATFORM:
-        return PEAK_FLOPS_BY_PLATFORM[plat], "datasheet"
-    if plat not in _MEASURED_PEAK:
-        _MEASURED_PEAK[plat] = measured_peak_flops()
-    return _MEASURED_PEAK[plat], "measured"
+        device_kind = jax.devices()[0].device_kind
+    entry = DEVICE_PEAKS.get(device_kind)
+    if entry is None:
+        raise KeyError(f"no published peak for device kind {device_kind!r}; "
+                       f"known kinds: {sorted(DEVICE_PEAKS)}")
+    return entry["flops_bf16"], entry["source"]
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

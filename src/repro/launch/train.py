@@ -21,6 +21,7 @@ import jax
 
 from repro.configs import registry
 from repro.data import synthetic
+from repro.launch.compile_cache import use_compile_cache
 from repro.train import optimizer as opt_mod
 from repro.train import train_step as ts_mod
 from repro.train import trainer as trainer_mod
@@ -38,6 +39,7 @@ def main():
     ap.add_argument("--global-batch", type=int, default=8)
     ap.add_argument("--multi-pod", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = registry.get_smoke(args.arch) if args.smoke else registry.get(args.arch)
     tcfg = ts_mod.TrainConfig(

@@ -5,7 +5,11 @@ mesh: stage states are replicated per the model's `shard_specs` (R/B are
 tiny), the feature batch shards its leading dim over the data-parallel
 axes, and the output comes back with the same layout — so a fleet-scale
 feature stream (millions of rows) fans out across the mesh with zero
-resharding inside the step.
+resharding inside the step.  The transform is row-independent, so it runs
+under `shard_map`: each device transforms its own rows.  That is also what
+lets a Pallas-backed model serve on a mesh — the partitioner cannot split
+a Mosaic kernel, and refuses one in a multi-device program outside a
+`shard_map`.
 
     mesh = make_production_mesh()
     step = dr_serve.make_dr_transform(model, mesh)
@@ -23,6 +27,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.dist import sharding as shard_rules
+from repro.launch.mesh import require_auto_axes
 from repro.serve.batching import BoundedCompileCache
 
 
@@ -40,6 +45,7 @@ def make_dr_transform(model, mesh: Mesh, *, batch_size: Optional[int] = None,
     `ensemble`: compile for a k-member ensemble state instead (states carry
     a leading (k,) axis; output gains a leading k dim).
     """
+    require_auto_axes(mesh)
     dax = shard_rules.batch_axes(mesh)
     n_dp = shard_rules.axis_size(mesh, dax)
     shard_batch = bool(dax) and n_dp > 1 and \
@@ -56,11 +62,13 @@ def make_dr_transform(model, mesh: Mesh, *, batch_size: Optional[int] = None,
     else:
         fn = model.transform
 
+    ospec = P(None, dax) if ensemble and shard_batch else bspec
+    per_shard = jax.shard_map(fn, mesh=mesh, in_specs=(sspec, bspec),
+                              out_specs=ospec, check_vma=False)
     return jax.jit(
-        fn,
+        per_shard,
         in_shardings=(_to_sh(sspec, mesh), NamedSharding(mesh, bspec)),
-        out_shardings=NamedSharding(mesh, P(None, dax) if ensemble and shard_batch
-                                    else bspec),
+        out_shardings=NamedSharding(mesh, ospec),
     )
 
 

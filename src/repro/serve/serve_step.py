@@ -19,6 +19,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import config_hash
 from repro.dist import sharding as shard_rules
+from repro.launch.mesh import require_auto_axes
 from repro.models import api
 from repro.models.config import ArchConfig
 from repro.serve.batching import BoundedCompileCache
@@ -45,6 +46,7 @@ def make_prefill(cfg: ArchConfig, mesh: Mesh, params_like: PyTree,
                  cache: BoundedCompileCache = None):
     """`cache=None` uses the module-level LRU; a `DRService` passes its own
     so LM steps and DR bucket programs share one bounded cache."""
+    require_auto_axes(mesh)
     key = ("prefill", config_hash(cfg), mesh, _tree_sig(params_like),
            _tree_sig(batch_like), cache_size)
     return (cache if cache is not None else _CACHE).get_or_build(
@@ -73,6 +75,7 @@ def _build_prefill(cfg: ArchConfig, mesh: Mesh, params_like: PyTree,
 
 def make_decode(cfg: ArchConfig, mesh: Mesh, params_like: PyTree, cache_like: PyTree,
                 *, cache: BoundedCompileCache = None):
+    require_auto_axes(mesh)
     key = ("decode", config_hash(cfg), mesh, _tree_sig(params_like),
            _tree_sig(cache_like))
     return (cache if cache is not None else _CACHE).get_or_build(

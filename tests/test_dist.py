@@ -13,6 +13,8 @@ import pytest
 from repro.dist import compress, sharding
 from repro.launch import roofline
 
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
 
 class TestShardingRules:
     def _mesh(self):
@@ -95,9 +97,9 @@ print("COMPRESS_OK corr=%.3f" % corr)
 
 @pytest.mark.slow
 def test_compressed_allreduce_8dev():
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", COMPRESS_SCRIPT], env=env,
-                         capture_output=True, text=True, cwd="/root/repo")
+                         capture_output=True, text=True, cwd=REPO)
     assert out.returncode == 0, out.stderr[-3000:]
     assert "COMPRESS_OK" in out.stdout
 
@@ -139,9 +141,9 @@ print("MOE_PARITY_OK lb=%.3f" % float(aux_sh["moe_lb"]))
 def test_moe_shard_map_parity_8dev():
     """a2a expert-parallel MoE == single-device math (capacity high enough
     that neither path drops tokens)."""
-    env = dict(os.environ, PYTHONPATH="src")
+    env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, "-c", MOE_PARITY_SCRIPT], env=env,
-                         capture_output=True, text=True, cwd="/root/repo")
+                         capture_output=True, text=True, cwd=REPO)
     assert out.returncode == 0, out.stderr[-3000:]
     assert "MOE_PARITY_OK" in out.stdout
 

@@ -171,7 +171,7 @@ class TestWALKillNine:
             "while True:\n"
             "    wal.append(('rec', i, 'x' * 64))\n"
             "    i += 1\n")
-        env = dict(os.environ)
+        env = dict(os.environ, JAX_PLATFORMS="cpu")
         proc = subprocess.Popen([sys.executable, "-c", child, p, src],
                                 stdout=subprocess.PIPE, env=env)
         try:

@@ -129,7 +129,8 @@ def test_cli_writes_regression_compatible_rows(tmp_path):
         [sys.executable, "-m", "repro.kernels.resource_model",
          "--json", str(out)],
         capture_output=True, text=True, cwd=REPO,
-        env={**os.environ, "PYTHONPATH": os.path.join(REPO, "src")},
+        env={**os.environ, "PYTHONPATH": os.path.join(REPO, "src"),
+             "JAX_PLATFORMS": "cpu"},
         timeout=120)
     assert proc.returncode == 0, proc.stderr
     rows = json.loads(out.read_text())

@@ -504,10 +504,12 @@ MULTIDEV_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from repro.core.execution import Execution
 from repro.dr import DRModel, EASIStage, RPStage
-from repro.serve import DRService, BucketPolicy, dr_serve
+from repro.launch.mesh import make_mesh
+from repro.serve import DRService, BucketPolicy, dr_serve, serve_step
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 model = DRModel(stages=(RPStage(32, 16), EASIStage.rotation(16, 8)))
 st = model.init(jax.random.PRNGKey(0))
 
@@ -535,14 +537,40 @@ for rows in (3, 17, 63):
                                np.asarray(model.transform(st, xr)),
                                rtol=1e-5, atol=1e-6)
 assert svc.cache.misses == 3
+
+# a Pallas-backed model serves on the same mesh: the transform runs per
+# shard, which is what lets the Mosaic kernel into a multi-device program
+kern = DRModel(stages=model.stages, execution=Execution(backend="pallas"))
+ksvc = DRService(mesh=mesh, buckets=BucketPolicy(min_bucket=8, max_bucket=64))
+ksvc.register("k", kern, st)
+yk = ksvc.transform("k", x_even)
+np.testing.assert_allclose(np.asarray(yk), np.asarray(model.transform(st, x_even)),
+                           rtol=1e-5, atol=1e-6)
+assert len(yk.sharding.device_set) == 8 and not yk.sharding.is_fully_replicated
+
+# jax.make_mesh's default Explicit axes are refused up front, with the fix
+# named, at every entry point that takes a caller's mesh
+explicit = jax.make_mesh((4, 2), ("data", "model"))
+refusals = (lambda: DRService(mesh=explicit),
+            lambda: dr_serve.dr_transform(model, st, x_even, mesh=explicit),
+            lambda: serve_step.make_prefill(None, explicit, {}, {}, 8),
+            lambda: serve_step.make_decode(None, explicit, {}, {}))
+for call in refusals:
+    try:
+        call()
+    except ValueError as e:
+        assert "AxisType.Auto" in str(e) and "make_mesh" in str(e), e
+    else:
+        raise AssertionError("an Explicit mesh was accepted")
 print("MULTIDEV_SERVE_OK")
 """
 
 
 @pytest.mark.slow
 def test_ragged_batch_multidevice_subprocess():
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run([sys.executable, "-c", MULTIDEV_SCRIPT],
-                         capture_output=True, text=True, cwd="/root/repo",
+                         capture_output=True, text=True, cwd=repo,
                          env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
                               "JAX_PLATFORMS": "cpu"})
     assert out.returncode == 0, out.stderr[-3000:]
