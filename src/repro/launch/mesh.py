@@ -10,8 +10,10 @@ Every axis is `AxisType.Auto`: the models and serving steps place arrays
 with explicit `in_shardings`/`out_shardings` and leave the propagation to
 the partitioner.  `jax.make_mesh` defaults to `Explicit` axes, under which
 gathers such as the embedding lookup need a per-op `out_sharding` and fail
-with `ShardingTypeError`; entry points that accept a caller's mesh call
-`require_auto_axes` so such a mesh is refused up front.
+with `ShardingTypeError`; the factories that build a program over a
+caller's mesh (`dr_serve.make_dr_transform`, `serve_step.make_prefill` /
+`make_decode`) call `require_auto_axes`, so such a mesh is refused before
+anything is traced.
 """
 
 from __future__ import annotations

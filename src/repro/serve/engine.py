@@ -53,7 +53,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from repro.kernels import autotune
-from repro.launch.mesh import require_auto_axes
 from repro.serve import dr_serve, serve_step
 from repro.serve.batching import (BoundedCompileCache, BucketPolicy,
                                   MicroBatcher, Ticket)
@@ -123,8 +122,6 @@ class DRService:
                  data_dir: Optional[str] = None):
         if not 0.0 <= update_fraction <= 1.0:
             raise ValueError("update_fraction must be in [0, 1]")
-        if mesh is not None:
-            require_auto_axes(mesh)
         self.mesh = mesh
         self.buckets = buckets
         self.clock: Clock = clock if clock is not None else MonotonicClock()

@@ -36,17 +36,19 @@ LM_SMALL = dataclasses.replace(chip_smoke.LM_SMOLLM, cfg=smollm_135m.SMOKE,
 def test_dr_phase_matches_reference(name):
     case = DR_SMALL[name]
     report, svc = chip_smoke.dr_phase(case)
-    for key in ("err_serve", "err_serve_and_update", "err_state_delta",
+    for key in ("err_serve", "err_serve_and_update",
                 "err_serve_after_promote"):
-        assert 0.0 <= report[key] <= case.tol_out, (key, report)
+        assert 0.0 <= report[key] <= chip_smoke.TOL_DR_OUT, (key, report)
+    for key in ("err_state_delta", "err_serve_promote_delta"):
+        assert 0.0 <= report[key] <= chip_smoke.TOL_DR_DELTA, (key, report)
     assert report["autotunes"] >= 1
     assert svc.registry.get("dr").version == 1
 
 
 def test_lm_phase_matches_reference():
     report = chip_smoke.lm_phase(LM_SMALL)
-    assert report["err_prefill"] <= LM_SMALL.tol_logits
-    assert report["err_decode"] <= LM_SMALL.tol_logits
+    assert report["err_prefill"] <= chip_smoke.TOL_LM_LOGITS
+    assert report["err_decode"] <= chip_smoke.TOL_LM_LOGITS
     assert len(report["tokens"]) == LM_SMALL.batch
     assert all(len(t) == LM_SMALL.decode_steps for t in report["tokens"])
 

@@ -548,10 +548,13 @@ np.testing.assert_allclose(np.asarray(yk), np.asarray(model.transform(st, x_even
                            rtol=1e-5, atol=1e-6)
 assert len(yk.sharding.device_set) == 8 and not yk.sharding.is_fully_replicated
 
-# jax.make_mesh's default Explicit axes are refused up front, with the fix
-# named, at every entry point that takes a caller's mesh
+# jax.make_mesh's default Explicit axes are refused, with the fix named, at
+# every entry point that takes a caller's mesh; DRService meets the refusal
+# when it builds its first sharded program
 explicit = jax.make_mesh((4, 2), ("data", "model"))
-refusals = (lambda: DRService(mesh=explicit),
+esvc = DRService(mesh=explicit, buckets=BucketPolicy(min_bucket=8, max_bucket=64))
+esvc.register("m", model, st)
+refusals = (lambda: esvc.transform("m", x_even),
             lambda: dr_serve.dr_transform(model, st, x_even, mesh=explicit),
             lambda: serve_step.make_prefill(None, explicit, {}, {}, 8),
             lambda: serve_step.make_decode(None, explicit, {}, {}))
