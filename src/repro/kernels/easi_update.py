@@ -109,5 +109,6 @@ def easi_apply(
         out_shape=jax.ShapeDtypeStruct((n_pad, m_pad), b_mat.dtype),
         scratch_shapes=[pltpu.VMEM((n_pad, n_pad), jnp.float32)],
         interpret=interpret,
+        name="easi_apply",
     )(y_p, b_p)
     return out[:n, :m]

@@ -112,5 +112,6 @@ def fused_transform(
         out_shape=jax.ShapeDtypeStruct((rows_pad, n_pad), b_mat.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bp), jnp.float32)],
         interpret=interpret,
+        name="fused_transform",
     )(x_p, r_p, b_p)
     return out[:rows, :n]

@@ -79,5 +79,6 @@ def ternary_matmul(
         out_specs=pl.BlockSpec((bm, bp), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((bp_pad, mp_pad), x.dtype),
         interpret=interpret,
+        name="ternary_matmul",
     )(x_p, r_p)
     return out[:b, :p]

@@ -97,12 +97,15 @@ class BoundedCompileCache:
     (model, mesh, layout) jits in: same O(1) lookup, but eviction actually
     drops the jitted closure (and with it the mesh / executable), and the
     counters let tests pin the compile count of a serving scenario.
+    With a `tracker` (an `slo.SLOTracker`), each build on a miss is timed
+    as its `cache.build` stage, with the compiles it ran.
     """
 
-    def __init__(self, maxsize: int = 64):
+    def __init__(self, maxsize: int = 64, tracker: Optional[Any] = None):
         if maxsize < 1:
             raise ValueError("maxsize must be >= 1")
         self.maxsize = maxsize
+        self._tracker = tracker
         self._d: "collections.OrderedDict[Hashable, Any]" = collections.OrderedDict()  # guarded-by: _lock
         self._lock = threading.Lock()
         self.hits = 0  # guarded-by: _lock
@@ -125,7 +128,11 @@ class BoundedCompileCache:
                 self.hits += 1
                 return self._d[key]
         # build outside the lock (jit tracing can be slow / re-entrant)
-        fn = build()
+        if self._tracker is None:
+            fn = build()
+        else:
+            with self._tracker.span("cache.build"):
+                fn = build()
         with self._lock:
             if key not in self._d:
                 self.misses += 1

@@ -44,9 +44,11 @@ Typical use:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
-from typing import Any, Callable, Dict, Hashable, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, Hashable, Iterator, Optional,
+                    Sequence, Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -143,9 +145,9 @@ class DRService:
                 LocalBus().attach("solo"), role="leader", quorum=1,
                 data_dir=data_dir)
         self.registry = registry if registry is not None else ModelRegistry()
-        self.cache = BoundedCompileCache(compile_cache_size)
+        self.slo = SLOTracker(clock=self.clock)
+        self.cache = BoundedCompileCache(compile_cache_size, tracker=self.slo)
         self.batcher = MicroBatcher(max_queue=max_queue)
-        self.slo = SLOTracker()
         self.update_fraction = update_fraction
         # train-while-serve bookkeeping (per model name).  All three dicts
         # are mutated from caller threads AND read by promote(), so every
@@ -184,6 +186,17 @@ class DRService:
                 lock = self._tws_locks[name] = threading.Lock()
             return lock
 
+    @contextlib.contextmanager
+    def _tws_lock_timed(self, name: str) -> Iterator[None]:
+        """Hold `_tws_lock(name)`, its acquire timed as `tws.lock_wait`."""
+        lock = self._tws_lock(name)
+        with self.slo.span("tws.lock_wait"):
+            lock.acquire()
+        try:
+            yield
+        finally:
+            lock.release()
+
     # ---- registry facade ---------------------------------------------------
     def register(self, name: str, model: Any, state: PyTree, *,
                  ensemble: Optional[int] = None, replace: bool = False) -> int:
@@ -212,6 +225,10 @@ class DRService:
         lock, so a concurrent `serve_and_update` either lands before the
         pop (its update is in the promoted state) or after the promote
         (it chains onto the newly-live state) — never in between."""
+        with self.slo.span("promote"):
+            return self._promote(name, version)
+
+    def _promote(self, name: str, version: Optional[int]) -> int:
         with self._tws_lock(name):
             if version is None:
                 with self._tws_guard:
@@ -367,9 +384,12 @@ class DRService:
                 if isinstance(name, _StepKey):
                     # steps are independent (never coalesced): one failing
                     # step fails only its own ticket, the rest still run
+                    stage = f"step.{name.kind}"
                     for work, t in items:
+                        t_start = self.clock.now()
                         try:
-                            out = work.fn(*work.args)
+                            with self.slo.span(stage):
+                                out = work.fn(*work.args)
                         except Exception as e:  # noqa: BLE001
                             t._fail(e)
                             continue
@@ -379,47 +399,57 @@ class DRService:
                         # record BEFORE resolve: a waiter woken by the
                         # ticket must find its sample already counted
                         self._record_slo(str(name.tag), name.kind, t,
-                                         t_flush)
+                                         started=t_start, flushed=t_flush)
                         t._resolve(out)
                     continue
-                snap = self.registry.get(name)
-                # validate every payload against the FLUSH-TIME snapshot:
-                # `register(replace=True)` may have swapped the model since
-                # submit, and a stale-shaped request must fail alone with a
-                # clear message — not blow up the whole group inside
-                # jnp.concatenate with an opaque shape error
-                good = []
-                for payload, t in items:
-                    if payload.ndim != 2 or \
-                            payload.shape[-1] != snap.model.in_dim:
-                        t._fail(ValueError(
-                            f"request shaped {tuple(payload.shape)} no longer "
-                            f"matches {name!r} at flush time (model expects "
-                            f"(B, {snap.model.in_dim}) — it was replaced "
-                            f"after this request was submitted)"))
-                    else:
-                        good.append((payload, t))
-                if not good:
-                    continue
-                tickets = [t for _, t in good]
-                xcat = good[0][0] if len(good) == 1 else \
-                    jnp.concatenate([p for p, _ in good], axis=0)
-                ycat = self._serve_rows(snap, xcat)
-                # _serve_rows consumes max_bucket rows per device batch
-                n_batches += -(-xcat.shape[0] // self.buckets.max_bucket)
-                off = 0
-                for t in tickets:
-                    sl = ycat[:, off:off + t.rows] if snap.ensemble \
-                        else ycat[off:off + t.rows]
-                    off += t.rows
-                    self._record_slo(name, self.buckets.bucket_for(t.rows),
-                                     t, t_flush)
-                    t._resolve(sl)
+                with self.slo.span("flush.dr"):
+                    n_batches += self._flush_dr(name, items, t_flush)
             except Exception as e:          # noqa: BLE001 — fail the tickets
                 for t in tickets:
                     if not t.done:
                         t._fail(e)
         return n_batches
+
+    def _flush_dr(self, name: str, items: Sequence[Tuple[Any, Ticket]],
+                  t_flush: float) -> int:
+        """One drained DR group: coalesce its payloads, serve them in
+        bucketed batches, resolve each ticket with its rows.  Returns the
+        device batches run."""
+        with self.slo.span("flush.coalesce"):
+            snap = self.registry.get(name)
+            # validate every payload against the FLUSH-TIME snapshot:
+            # `register(replace=True)` may have swapped the model since
+            # submit, and a stale-shaped request must fail alone with a
+            # clear message — not blow up the whole group inside
+            # jnp.concatenate with an opaque shape error
+            good = []
+            for payload, t in items:
+                if payload.ndim != 2 or \
+                        payload.shape[-1] != snap.model.in_dim:
+                    t._fail(ValueError(
+                        f"request shaped {tuple(payload.shape)} no longer "
+                        f"matches {name!r} at flush time (model expects "
+                        f"(B, {snap.model.in_dim}) — it was replaced "
+                        f"after this request was submitted)"))
+                else:
+                    good.append((payload, t))
+            if not good:
+                return 0
+            xcat = good[0][0] if len(good) == 1 else \
+                jnp.concatenate([p for p, _ in good], axis=0)
+        with self.slo.span("serve_rows"):
+            ycat = self._serve_rows(snap, xcat)
+        with self.slo.span("flush.resolve"):
+            off = 0
+            for _, t in good:
+                sl = ycat[:, off:off + t.rows] if snap.ensemble \
+                    else ycat[off:off + t.rows]
+                off += t.rows
+                self._record_slo(name, self.buckets.bucket_for(t.rows), t,
+                                 started=t_flush, flushed=t_flush)
+                t._resolve(sl)
+        # _serve_rows consumes max_bucket rows per device batch
+        return -(-xcat.shape[0] // self.buckets.max_bucket)
 
     # ---- LM steps through the same queue ------------------------------------
     # The *_step builders are the single source of truth for how an LM step
@@ -506,6 +536,10 @@ class DRService:
         `_fused_update_fn`); a `register(replace=True)` racing the
         pre-build is detected by config-hash mismatch under the lock and
         rebuilt there (rare, waived)."""
+        with self.slo.span("serve_and_update"):
+            return self._serve_and_update(name, x)
+
+    def _serve_and_update(self, name: str, x: jax.Array) -> jax.Array:
         snap0 = self.registry.get(name)
         self._check_request(snap0, x)
         if snap0.ensemble:
@@ -520,7 +554,7 @@ class DRService:
             return self._serve_rows(snap0, x)
 
         fused = self._fused_update_fn(snap0, x)
-        with self._tws_lock(name):
+        with self._tws_lock_timed(name):
             snap = self.registry.get(name)
             if snap.chash != snap0.chash:
                 # a replace raced the pre-build: re-validate and rebuild
@@ -585,29 +619,32 @@ class DRService:
             "compile_cache": self.cache.stats(),
             "queue": self.batcher.stats(),
             "slo": self.slo.report(),
+            "stages": self.slo.stages(),
             "deadline_met": met,
             "deadline_missed": missed,
         }
 
     # ---- internals ---------------------------------------------------------
-    def _record_slo(self, name: str, bucket: Hashable, t: Ticket,
-                    t_flush: float) -> None:
+    def _record_slo(self, name: str, bucket: Hashable, t: Ticket, *,
+                    started: float, flushed: float) -> None:
         # `bucket` is the ticket's NOMINAL size class (bucket_for(rows)) —
         # a coalesced flush may physically run a larger batch, but keeping
         # attribution per-request gives each size class one stable cell.
-        # `deadline_ok` is judged on FLUSH START, not post-compute
-        # resolution: max_delay_ms bounds the batching window (how long the
-        # queue may hold a request), so a deadline-triggered flush that
-        # starts on time IS met — judging on resolution would brand every
+        # The queue delay ends when the ticket's own work `started` (a
+        # step drained behind others waits for them too).  `deadline_ok`
+        # is judged on FLUSH START, not post-compute resolution:
+        # max_delay_ms bounds the batching window (how long the queue may
+        # hold a request), so a deadline-triggered flush that starts on
+        # time IS met — judging on resolution would brand every
         # deadline-expiry flush a miss by construction.
         if t.submitted_at is None:
             return
         now = self.clock.now()
         self.slo.record(
             name, bucket,
-            queue_delay_ms=max(0.0, t_flush - t.submitted_at),
+            queue_delay_ms=max(0.0, started - t.submitted_at),
             e2e_ms=max(0.0, now - t.submitted_at),
-            deadline_ok=None if t.deadline is None else t_flush <= t.deadline)
+            deadline_ok=None if t.deadline is None else flushed <= t.deadline)
 
     def _check_request(self, snap: Snapshot, x: jax.Array) -> None:
         if x.ndim != 2 or x.shape[-1] != snap.model.in_dim:

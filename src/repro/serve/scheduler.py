@@ -78,8 +78,6 @@ class DeadlineScheduler:
         self._stop = False  # guarded-by: _cond
         self._drain_on_stop = True  # guarded-by: _cond
         self._thread: Optional[threading.Thread] = None
-        self.flushes = 0          # batches flushed by this scheduler
-        self.polls = 0
         if start:
             self.start()
 
@@ -138,14 +136,13 @@ class DeadlineScheduler:
         """Flush every group that is due (full, or oldest deadline expired)
         at the clock's current now.  Returns device batches run.  Safe to
         call from any thread, any time — the loop and manual pumping
-        compose (a group drains exactly once)."""
-        self.polls += 1
+        compose (a group drains exactly once).  A poll that flushes is
+        timed as the service's `poll` stage."""
         due, _ = self._scan(self.service.clock.now())
         if not due:
             return 0
-        n = self.service.flush(keys=due)
-        self.flushes += n
-        return n
+        with self.service.slo.span("poll"):
+            return self.service.flush(keys=due)
 
     def next_deadline(self) -> Optional[float]:
         """Earliest absolute deadline (clock ms) over queued tickets, or
@@ -189,7 +186,7 @@ class DeadlineScheduler:
                     # the flush starts inside the budget on a real clock
                     clock.wait(self._cond, dl - now - self.wake_lead_ms)
         if self._drain_on_stop:
-            self.flushes += self.service.flush()
+            self.service.flush()
 
     # ---- lifecycle ---------------------------------------------------------
     @property
@@ -229,7 +226,7 @@ class DeadlineScheduler:
             if t.is_alive():
                 raise RuntimeError("scheduler loop did not stop in time")
         elif drain:
-            self.flushes += self.service.flush()
+            self.service.flush()
 
     def __enter__(self) -> "DeadlineScheduler":
         return self

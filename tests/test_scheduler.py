@@ -20,7 +20,8 @@ from repro.serve import (DRService, DeadlineScheduler, ModelRegistry,
                          MonotonicClock, QueueFull, SchedulerClosed,
                          VirtualClock)
 from repro.serve.batching import MicroBatcher
-from repro.serve.slo import LatencyStats, SLOTracker
+from repro.serve.slo import (LatencyStats, SLOTracker, snapshot_delta,
+                             snapshot_percentile)
 
 jax.config.update("jax_enable_x64", False)
 
@@ -323,15 +324,24 @@ class TestSLO:
         assert s.percentile(50) == 100.0                 # window forgot 1..3
         assert s.max_ms == 100.0
 
-    def test_histogram_pow2_bins(self):
+    @pytest.mark.parametrize("p", [0, 10, 50, 90, 95, 99, 100])
+    def test_cumulative_bins_read_window_percentiles(self, p):
+        """A percentile of the window between two snapshots, read from the
+        cumulative bins, lies within 2.2% of the exact nearest-rank one
+        over that window's samples — past the 4096-sample deque."""
+        rng = np.random.default_rng(1234)
+        before, window = rng.lognormal(0.0, 1.5, (2, 6000))
         s = LatencyStats()
-        for v in (0.0, 0.2, 0.25, 0.5, 3.0):
+        for v in before:
             s.record(v)
-        hist = s.histogram()
-        assert hist == {"le_0.25ms": 3, "le_0.5ms": 1, "le_4ms": 1}
-        assert sum(hist.values()) == 5
-        assert LatencyStats().histogram() == {}
+        a = s.snapshot()
+        for v in window:
+            s.record(v)
+        got = snapshot_percentile(snapshot_delta(a, s.snapshot()), p)
+        exact = np.sort(window)[max(1, int(np.ceil(len(window) * p / 100))) - 1]
+        assert abs(got - exact) <= 0.022 * exact
         assert LatencyStats().percentile(50) is None
+        assert snapshot_percentile(LatencyStats().snapshot(), 50) is None
 
     def test_tracker_report_shape(self):
         tr = SLOTracker()
