@@ -12,7 +12,13 @@ deployment; this is that story at service level.  One `DRService` owns:
     bucketed batch shapes, so the compile universe is O(log max_bucket)
     programs per model instead of one per client batch size, all held in
     a bounded LRU compile cache (evicting actually frees the jitted
-    closure and any mesh it pins);
+    closure and any mesh it pins).  Without a mesh, a flush of host
+    (numpy) payloads joins, zero-pads and slices them in numpy: one
+    transfer into the bucket program per batch, one copy of the answers
+    back, and tickets that resolve with numpy rows — no XLA program per
+    mix of request sizes.  Device (`jax.Array`) payloads, a group mixing
+    the two, and every group of a mesh service are joined, padded and
+    sliced on the device and resolve with `jax.Array`s;
   * train-while-serve — `serve_and_update` answers a request with the
     LIVE state while streaming the same traffic (a configurable fraction
     of it) through `model.update` into a STAGED state; `promote()` makes
@@ -46,12 +52,14 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import threading
 from typing import (Any, Callable, Dict, Hashable, Iterator, Optional,
                     Sequence, Tuple)
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh
 
 from repro.kernels import autotune
@@ -73,6 +81,16 @@ def _pad_rows(x: jax.Array, bucket: int) -> jax.Array:
         return x
     return jnp.concatenate(
         [x, jnp.zeros((pad,) + x.shape[1:], x.dtype)], axis=0)
+
+
+@dataclasses.dataclass(frozen=True)
+class _HostRows:
+    """A flush group joined and zero-padded on the host: `rows` request
+    rows laid out as the bucket batches `_serve_rows` runs, i.e. chunk
+    `i` of `max_bucket` rows starts at row `i * max_bucket` and `buf`
+    ends at the last chunk's bucket."""
+    buf: np.ndarray
+    rows: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -177,6 +195,7 @@ class DRService:
         self.served_rows = 0                        # guarded-by: _metrics_lock
         self.padded_rows = 0                        # guarded-by: _metrics_lock
         self.batches_run = 0                        # guarded-by: _metrics_lock
+        self.host_batches = 0                       # guarded-by: _metrics_lock
         self.autotunes = 0                          # guarded-by: _metrics_lock
 
     def _tws_lock(self, name: str) -> threading.Lock:
@@ -342,9 +361,12 @@ class DRService:
     def submit(self, name: str, x: jax.Array, *,
                max_delay_ms: Optional[float] = None) -> Ticket:
         """Enqueue a ragged request; returns a Ticket resolved by `flush`.
-        Raises `batching.QueueFull` past max_queue rows (backpressure;
-        transient — retry after a flush) and `ValueError` for requests
-        larger than max_queue outright (never admittable — chunk them).
+        On a service without a mesh the ticket resolves with the kind of
+        array it was given: a host (numpy) request with numpy rows, a
+        `jax.Array` request with a `jax.Array` (see `flush`).  Raises
+        `batching.QueueFull` past max_queue rows (backpressure; transient
+        — retry after a flush) and `ValueError` for requests larger than
+        max_queue outright (never admittable — chunk them).
         `max_delay_ms` sets the ticket's deadline relative to now — a
         `DeadlineScheduler` wrapping this service flushes the bucket when
         it expires; without one it only bounds the SLO miss accounting."""
@@ -372,10 +394,16 @@ class DRService:
 
     def flush(self, keys: Optional[Sequence[Hashable]] = None) -> int:
         """Coalesce the queue into bucketed batches, run them, resolve every
-        ticket with its own rows.  With `keys`, only those groups flush
-        (the deadline scheduler's partial flush).  Returns the number of
-        device batches THIS call ran (counted locally — a concurrent
-        caller's batches never leak into the return value)."""
+        ticket with its own rows.  A DR group whose payloads are all host
+        arrays, on a service without a mesh, is joined and zero-padded to
+        its buckets in numpy, sent in one transfer per batch, and its
+        answers come back in one copy and are sliced on the host (numpy
+        results); otherwise the join, pad and per-ticket slices are
+        device ops (`jax.Array` results), which compile per new mix of
+        request sizes.  With `keys`, only those groups flush (the deadline
+        scheduler's partial flush).  Returns the number of device batches
+        THIS call ran (counted locally — a concurrent caller's batches
+        never leak into the return value)."""
         n_batches = 0
         for name, items in self.batcher.drain(keys):
             tickets = [t for _, t in items]
@@ -413,15 +441,17 @@ class DRService:
     def _flush_dr(self, name: str, items: Sequence[Tuple[Any, Ticket]],
                   t_flush: float) -> int:
         """One drained DR group: coalesce its payloads, serve them in
-        bucketed batches, resolve each ticket with its rows.  Returns the
-        device batches run."""
+        bucketed batches, resolve each ticket with its rows.  Host
+        payloads are joined and padded in numpy (`_host_rows`) and the
+        answers sliced from one host copy; any device payload keeps the
+        whole group on the device.  Returns the device batches run."""
         with self.slo.span("flush.coalesce"):
             snap = self.registry.get(name)
             # validate every payload against the FLUSH-TIME snapshot:
             # `register(replace=True)` may have swapped the model since
             # submit, and a stale-shaped request must fail alone with a
-            # clear message — not blow up the whole group inside
-            # jnp.concatenate with an opaque shape error
+            # clear message — not blow up the whole group inside the join
+            # with an opaque shape error
             good = []
             for payload, t in items:
                 if payload.ndim != 2 or \
@@ -435,10 +465,18 @@ class DRService:
                     good.append((payload, t))
             if not good:
                 return 0
-            xcat = good[0][0] if len(good) == 1 else \
-                jnp.concatenate([p for p, _ in good], axis=0)
+            payloads = [p for p, _ in good]
+            host = self.mesh is None and \
+                all(isinstance(p, np.ndarray) for p in payloads)
+            if host:
+                xcat = self._host_rows(payloads)
+            else:
+                xcat = payloads[0] if len(payloads) == 1 else \
+                    jnp.concatenate(payloads, axis=0)
         with self.slo.span("serve_rows"):
             ycat = self._serve_rows(snap, xcat)
+            if host:
+                ycat = np.asarray(ycat)     # the one copy of the answers
         with self.slo.span("flush.resolve"):
             off = 0
             for _, t in good:
@@ -449,7 +487,25 @@ class DRService:
                                  started=t_flush, flushed=t_flush)
                 t._resolve(sl)
         # _serve_rows consumes max_bucket rows per device batch
-        return -(-xcat.shape[0] // self.buckets.max_bucket)
+        return -(-off // self.buckets.max_bucket)
+
+    def _host_rows(self, payloads: Sequence[np.ndarray]) -> _HostRows:
+        """Join host payloads into one zero-filled numpy buffer laid out
+        as `_serve_rows`' bucket batches.  The dtype is the one a device
+        join would give (JAX's promotion, canonicalized), so the bucket
+        program and its answers are the device path's."""
+        dtype = jax.dtypes.canonicalize_dtype(
+            functools.reduce(jnp.promote_types, [p.dtype for p in payloads]))
+        rows = sum(p.shape[0] for p in payloads)
+        step = self.buckets.max_bucket
+        last = (rows - 1) // step * step        # first row of the last batch
+        buf = np.zeros((last + self.buckets.bucket_for(rows - last),
+                        payloads[0].shape[1]), dtype)
+        off = 0
+        for p in payloads:
+            buf[off:off + p.shape[0]] = p
+            off += p.shape[0]
+        return _HostRows(buf, rows)
 
     # ---- LM steps through the same queue ------------------------------------
     # The *_step builders are the single source of truth for how an LM step
@@ -605,6 +661,7 @@ class DRService:
             served = self.served_rows
             padded = self.padded_rows
             batches = self.batches_run
+            host_batches = self.host_batches
             autotunes = self.autotunes
         with self._tws_guard:
             updates = dict(self._updates)
@@ -613,6 +670,7 @@ class DRService:
             "served_rows": served,
             "padded_rows": padded,
             "batches_run": batches,
+            "host_batches": host_batches,
             "autotunes": autotunes,
             "updates_applied": updates,
             "staged": staged,
@@ -706,22 +764,33 @@ class DRService:
             self.autotunes += 1
         return prog
 
-    def _serve_rows(self, snap: Snapshot, x: jax.Array) -> jax.Array:
+    def _serve_rows(self, snap: Snapshot,
+                    x: jax.Array | _HostRows) -> jax.Array:
         """Run (R, m) rows through bucketed batches; returns (R, n) rows in
-        order ((k, R, n) for ensembles)."""
+        order ((k, R, n) for ensembles).  Given `_HostRows`, each batch is
+        a bucket-shaped view of its buffer, sent as it is, and the
+        bucket-padded outputs come back joined in the buffer's layout:
+        row r of the result (axis 1 for ensembles) is row r of the
+        buffer."""
+        host = isinstance(x, _HostRows)
+        total = x.rows if host else x.shape[0]
+        dtype = x.buf.dtype if host else x.dtype
         outs = []
         i, step = 0, self.buckets.max_bucket
-        while i < x.shape[0]:
-            chunk = x[i:i + step]
-            rows = chunk.shape[0]
+        while i < total:
+            rows = min(step, total - i)
             bucket = self.buckets.bucket_for(rows)
-            y = self._transform_fn(snap, bucket, x.dtype)(
-                snap.state, _pad_rows(chunk, bucket))
-            outs.append(y[:, :rows] if snap.ensemble else y[:rows])
+            fn = self._transform_fn(snap, bucket, dtype)
+            if host:
+                outs.append(fn(snap.state, x.buf[i:i + bucket]))
+            else:
+                y = fn(snap.state, _pad_rows(x[i:i + rows], bucket))
+                outs.append(y[:, :rows] if snap.ensemble else y[:rows])
             with self._metrics_lock:
                 self.padded_rows += bucket - rows
                 self.served_rows += rows
                 self.batches_run += 1
+                self.host_batches += int(host)
             i += rows
         if len(outs) == 1:
             return outs[0]

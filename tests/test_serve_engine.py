@@ -274,6 +274,118 @@ class TestMicroBatchedServing:
         assert mb.drain() == []
 
 
+class TestHostFlush:
+    """A flush of host (numpy) payloads joins, pads and slices them in
+    numpy around the same bucket programs as the device path."""
+
+    @staticmethod
+    def _flush(svc, name, xs):
+        tickets = [svc.submit(name, x) for x in xs]
+        assert svc.flush() == -(-sum(x.shape[0] for x in xs)
+                                // svc.buckets.max_bucket)
+        return [t.result() for t in tickets]
+
+    @staticmethod
+    def _xs(sizes, m=32, seed=0):
+        rng = np.random.default_rng(seed)
+        return [rng.standard_normal((s, m)).astype(np.float32)
+                for s in sizes]
+
+    @pytest.mark.parametrize("sizes, ensemble", [
+        ((3, 7, 1, 5), None),           # one batch of 16
+        ((30, 21, 9, 33, 2), None),     # 95 rows: two full batches and 31
+        ((5, 11, 3), 3),                # ensemble, (k, rows, n) answers
+        ((13, 30), 2),                  # ensemble over max_bucket
+    ])
+    def test_host_answers_and_counters_equal_the_device_path(
+            self, sizes, ensemble):
+        model = _model()
+        state = (model.init(jax.random.PRNGKey(4)) if ensemble is None else
+                 model.ensemble(ensemble).init(jax.random.PRNGKey(4)))
+        services = []
+        for _ in range(2):
+            svc = DRService(buckets=BucketPolicy(min_bucket=4, max_bucket=32))
+            svc.register("m", model, state, ensemble=ensemble)
+            services.append(svc)
+        xs = self._xs(sizes)
+        host = self._flush(services[0], "m", xs)
+        dev = self._flush(services[1], "m", [jnp.asarray(x) for x in xs])
+        for h, d, x in zip(host, dev, xs):
+            assert type(h) is np.ndarray and isinstance(d, jax.Array)
+            want = (x.shape[0], 8) if ensemble is None else \
+                (ensemble, x.shape[0], 8)
+            assert h.shape == want
+            np.testing.assert_array_equal(h, np.asarray(d))
+        mh, md = services[0].metrics(), services[1].metrics()
+        rows = sum(sizes)
+        last = rows % 32 or 32
+        for key, val in (("served_rows", rows), ("batches_run", -(-rows // 32)),
+                         ("padded_rows",
+                          services[0].buckets.bucket_for(last) - last)):
+            assert mh[key] == md[key] == val, key
+        assert mh["host_batches"] == mh["batches_run"]
+        assert md["host_batches"] == 0
+
+    def test_new_host_size_mixes_compile_nothing_after_warmup(self):
+        model = _model()
+        svc, _ = _service(model)                # buckets 4..32
+        svc.warmup("m")
+        events = []
+
+        def listen(event, secs, **kw):
+            if event == "/jax/core/compile/backend_compile_duration":
+                events.append(secs)
+
+        rng = np.random.default_rng(7)
+        mixes = [tuple(int(s) for s in rng.integers(1, 12, size=n))
+                 for n in (1, 2, 3, 2, 4, 1, 3)]
+        jax.monitoring.register_event_duration_secs_listener(listen)
+        try:
+            for i, sizes in enumerate(mixes):
+                self._flush(svc, "m", self._xs(sizes, seed=i))
+            host_compiles = len(events)
+            # the same mixes as device payloads compile their joins, pads
+            # and slices: the listener does see those
+            for i, sizes in enumerate(mixes):
+                self._flush(svc, "m", [jnp.asarray(x) for x in
+                                       self._xs(sizes, seed=i)])
+        finally:
+            jax.monitoring.unregister_event_duration_listener(listen)
+        assert host_compiles == 0
+        assert len(events) > 0
+        assert svc.metrics()["host_batches"] == len(mixes)
+
+    def test_a_mixed_group_takes_the_device_path(self):
+        model = _model()
+        svc, st = _service(model)
+        xh, xd = self._xs((5, 6))
+        xd = jnp.asarray(xd)
+        yh, yd = self._flush(svc, "m", [xh, xd])
+        assert isinstance(yh, jax.Array) and isinstance(yd, jax.Array)
+        alone, = self._flush(svc, "m", [xh])
+        assert type(alone) is np.ndarray
+        np.testing.assert_array_equal(np.asarray(yh), alone)
+        met = svc.metrics()
+        assert (met["batches_run"], met["host_batches"]) == (2, 1)
+
+    def test_stale_host_payload_fails_alone(self):
+        model = _model()                          # in_dim 32
+        svc, _ = _service(model)
+        stale = [svc.submit("m", x) for x in self._xs((5, 3))]
+        new_model = _model(m=16)                  # in_dim 16
+        svc.register("m", new_model, new_model.init(jax.random.PRNGKey(1)),
+                     replace=True)
+        fresh = svc.submit("m", self._xs((4,), m=16)[0])
+        svc.flush()
+        for t in stale:
+            with pytest.raises(ValueError, match="replaced"):
+                t.result()
+        y = fresh.result()
+        assert type(y) is np.ndarray and y.shape == (4, 8)
+        met = svc.metrics()
+        assert (met["served_rows"], met["host_batches"]) == (4, 1)
+
+
 class TestTrainWhileServe:
     def test_round_trip_equals_offline_fit(self):
         """Acceptance: register → serve_and_update → promote → transform.
