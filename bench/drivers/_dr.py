@@ -63,9 +63,13 @@ class DRSystem:
         self.svc.register(name, self.model, self.state0)
 
     def counters(self) -> Dict[str, Any]:
+        """The service's row and batch counters, its stages' histograms
+        (`stages`) and its SLO cells' (`slo`)."""
         m = self.svc.metrics()
-        return {k: m[k] for k in ("served_rows", "padded_rows",
-                                  "batches_run")}
+        out = {k: m[k] for k in ("served_rows", "padded_rows",
+                                 "batches_run", "host_batches", "stages")}
+        out["slo"] = self.svc.slo.snapshot()
+        return out
 
     def free(self) -> None:
         """Drop the service and the program's copy of the state."""

@@ -103,8 +103,12 @@ class Driver:
                         for p in schedule.sizes["prompt_tokens"]]
 
     def counters(self) -> Dict[str, Any]:
+        """Steps served, and the service's stages' and SLO cells'
+        histograms (`stages`, `slo`)."""
         with self._lock:
-            return {"steps": self._steps}
+            steps = self._steps
+        return {"steps": steps, "stages": self.svc.slo.stages(),
+                "slo": self.svc.slo.snapshot()}
 
     def _request(self, prompt: np.ndarray, n_tokens: int,
                  contexts: Optional[List[int]] = None):

@@ -20,6 +20,29 @@ lines of standard error list every number compared with its limit, and
 the last line of standard output is the result object.  Without an
 accelerator, or with fewer chips than the cell asks for, it exits 2 and
 prints no result.
+
+How to add a cell (new files and new BENCHMARK.json entries only):
+
+  bench/configs/<config>.json     the configuration as it is run, with
+                                  its "limits" for the check (a new
+                                  configuration only), and its plain
+                                  reference in bench/reference/
+  bench/traffic/<traffic>.json    the traffic mix, read by traffic.py
+  bench/drivers/<driver>.py       the path it drives, where no driver
+                                  drives it yet (a `Driver` class, see
+                                  drivers/__init__.py); its counters()
+                                  hand over what the readers read
+  bench/metrics/<metric>.py       one reader per per-layer metric, a thin
+                                  call to metrics/readers.py where it can
+  bench/tests/data/contexts/<cell>.json
+                                  the reader-test context: counters, host
+                                  record and program runs the cell's
+                                  readers read (test_bench_readers.py)
+
+and in BENCHMARK.json: the configuration (a new one only), the cell under
+"workloads", its per-layer metrics with "workloads": [<cell>], a new
+end-to-end metric if the cell reports one, and the cell's name added to
+the "workloads" list of each end-to-end metric it reports.
 """
 
 from __future__ import annotations

@@ -24,7 +24,8 @@ BENCH = ROOT / "bench"
 
 # ---- schedules and payloads -------------------------------------------------
 
-@pytest.mark.parametrize("name", ["steady", "chat", "train_while_serve"])
+@pytest.mark.parametrize("name", ["steady", "chat", "train_while_serve",
+                                  "saturate"])
 def test_every_seed_gets_the_same_schedule(name):
     spec = traffic.load(name)
     keys = tuple(k for k, v in spec.items()
