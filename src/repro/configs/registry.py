@@ -24,6 +24,7 @@ ARCH_IDS = [
     "zamba2_7b",
     "phi35_moe",
     "dbrx_132b",
+    "moonlight_16b_a3b",
 ]
 
 # assignment ids use dashes; keep a mapping for CLIs
@@ -38,6 +39,7 @@ ALIASES = {
     "zamba2-7b": "zamba2_7b",
     "phi3.5-moe-42b-a6.6b": "phi35_moe",
     "dbrx-132b": "dbrx_132b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 

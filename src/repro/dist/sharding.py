@@ -129,6 +129,10 @@ def cache_specs(cache: PyTree, mesh: Mesh) -> PyTree:
     dim 1 (batch) shards over the DP axes; for attention K/V caches dim 2
     (sequence) shards over "model" — sequence parallelism, so a long
     context's cache splits across the TP group instead of replicating.
+    A latent cache (`c_kv`, `k_pe`: (layers, batch, seq, width)) shards
+    its batch only: the absorbed decode reads every position of it for
+    every head, so it stays whole on each chip of a TP group.  Counters
+    riding with the cache (`pairs_*`) are replicated.
     """
     dax = batch_axes(mesh)
     flat, treedef = jax.tree_util.tree_flatten_with_path(cache)

@@ -49,6 +49,18 @@ def init_cache(cfg: ArchConfig, batch: int, cache_size: int) -> PyTree:
         return rwkv6.init_state(cfg, batch, cdt)
     if cfg.family == "zamba":
         return ssm.init_cache(cfg, batch, cache_size, cdt)
+    if cfg.mla is not None:
+        m = cfg.mla
+        cache = {
+            "c_kv": jnp.zeros((cfg.n_layers, batch, cache_size, m.kv_lora_rank), cdt),
+            "k_pe": jnp.zeros((cfg.n_layers, batch, cache_size, m.qk_rope_head_dim), cdt),
+            "len": jnp.zeros((), jnp.int32),
+            "pos": jnp.zeros((), jnp.int32),
+        }
+        if cfg.moe is not None and cfg.moe.dropless:
+            cache["pairs_prefill"] = jnp.zeros((cfg.moe.e_held,), jnp.int32)
+            cache["pairs_decode"] = jnp.zeros((cfg.moe.e_held,), jnp.int32)
+        return cache
     win = cfg.sliding_window
     keep = min(cache_size, win) if win else cache_size
     dh_k = cfg.dh // cfg.kv_rp if cfg.kv_rp else cfg.dh  # RP-sketched keys
@@ -63,8 +75,11 @@ def init_cache(cfg: ArchConfig, batch: int, cache_size: int) -> PyTree:
 def exact_param_counts(cfg: ArchConfig) -> Tuple[int, int]:
     """(total, active) param counts from the REAL param tree (eval_shape).
 
-    `active` discounts expert weights by top_k/E — the 6·N_active·D
-    convention for MoE model-FLOPs.
+    `active` discounts routed expert weights by top_k/E — the 6·N_active·D
+    convention for MoE model-FLOPs.  For a chip holding E_held of the E
+    experts the tree holds E_held of them, and top_k/E of those is the
+    expected top_k·E_held/E experts a token runs here.  Shared experts and
+    leading dense layers count whole.
     """
     import re
 

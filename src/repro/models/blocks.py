@@ -104,7 +104,7 @@ def _flash_forward(q, k, v, *, causal, window, cq, ck, q_offset, skv_true):
                 preferred_element_type=jnp.float32)
             return (acc_new, m_new, l_new), None
 
-        acc0 = jnp.zeros((b, hkv, g, cq, dh), jnp.float32)
+        acc0 = jnp.zeros((b, hkv, g, cq, v.shape[-1]), jnp.float32)
         m0 = jnp.full((b, hkv, g, cq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, hkv, g, cq), jnp.float32)
         (acc, m_run, l_run), _ = jax.lax.scan(kv_step, (acc0, m0, l0), (k, v, jnp.arange(nk)))
@@ -220,7 +220,7 @@ def _build_flash(causal, window, cq, ck, q_offset, skv_true):
 def flash_attention(
     q: jax.Array,            # (B, Sq, Hq, Dh)
     k: jax.Array,            # (B, Skv, Hkv, Dh)
-    v: jax.Array,            # (B, Skv, Hkv, Dh)
+    v: jax.Array,            # (B, Skv, Hkv, Dv)
     *,
     causal: bool = True,
     window: Optional[int] = None,
@@ -234,9 +234,12 @@ def flash_attention(
     custom VJP recomputes probability tiles from (q, k, lse) FlashAttention-
     style, so residuals are O(S·dh) instead of O(S²) — this is what lets the
     32k-prefill and 4k-train cells fit HBM.  (No double-backward support.)
+    Values may be narrower than queries and keys (latent attention: q/k
+    192 wide, v 128); the softmax scale is 1/sqrt(Dh).
     """
     b, sq, hq, dh = q.shape
     _, skv, hkv, _ = k.shape
+    dv = v.shape[-1]
     g = hq // hkv
 
     cq = min(q_chunk, sq)
@@ -253,11 +256,11 @@ def flash_attention(
     # keep compute dtype (bf16 in models); f32 only in accumulators/lse
     qg = q.reshape(b, nq, cq, hkv, g, dh)
     kc = k.reshape(b, nk, ck, hkv, dh).transpose(1, 0, 2, 3, 4)
-    vc = v.reshape(b, nk, ck, hkv, dh).transpose(1, 0, 2, 3, 4)
+    vc = v.reshape(b, nk, ck, hkv, dv).transpose(1, 0, 2, 3, 4)
 
     fa = _build_flash(causal, window, cq, ck, q_offset, skv)
-    out = fa(qg, kc, vc)                                     # (b,nq,cq,hkv,g,dh)
-    out = out.reshape(b, sq_pad, hq, dh)
+    out = fa(qg, kc, vc)                                     # (b,nq,cq,hkv,g,dv)
+    out = out.reshape(b, sq_pad, hq, dv)
     return out[:, :sq].astype(q.dtype)
 
 
@@ -287,6 +290,40 @@ def decode_attention(
     p = jax.nn.softmax(s_, axis=-1)
     out = jnp.einsum("bhgk,bkhd->bhgd", p, v_cache.astype(jnp.float32))
     return out.reshape(b, 1, hq, v_cache.shape[-1]).astype(q.dtype)
+
+
+def mla_decode_attention(
+    q_nope: jax.Array,       # (B, H, Dn)
+    q_pe: jax.Array,         # (B, H, Dr) rotated
+    c_kv: jax.Array,         # (B, S, C) normed latents of the cache
+    k_pe: jax.Array,         # (B, S, Dr) rotated, shared by every head
+    w_uk: jax.Array,         # (C, H, Dn) latent -> key (no rotary part)
+    w_uv: jax.Array,         # (C, H, Dv) latent -> value
+    cache_len: jax.Array,    # scalar int32 — valid prefix length
+    *,
+    scale: float,
+) -> jax.Array:
+    """One query per head over a latent cache, in the absorbed form
+    (DeepSeek-V2 §2.1.2): q_nope·W_UK is scored against the cached latents
+    and W_UV is applied after the weighted sum, so no per-head key or value
+    is ever built.  The cache is read in its own dtype (f32 accumulation);
+    no f32 copy of it is made.  Returns (B, H, Dv)."""
+    cdt = c_kv.dtype
+    f32 = jnp.float32
+    q_abs = jnp.einsum("bhd,chd->bhc", q_nope.astype(cdt), w_uk.astype(cdt),
+                       preferred_element_type=f32)
+    s = jnp.einsum("bhc,bsc->bhs", q_abs.astype(cdt), c_kv,
+                   preferred_element_type=f32)
+    s = s + jnp.einsum("bhr,bsr->bhs", q_pe.astype(cdt), k_pe,
+                       preferred_element_type=f32)
+    valid = jnp.arange(c_kv.shape[1]) < cache_len
+    s = jnp.where(valid[None, None], s * scale, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    ctx = jnp.einsum("bhs,bsc->bhc", p.astype(cdt), c_kv,
+                     preferred_element_type=f32)
+    out = jnp.einsum("bhc,chd->bhd", ctx.astype(cdt), w_uv.astype(cdt),
+                     preferred_element_type=f32)
+    return out.astype(cdt)
 
 
 # ---------------------------------------------------------------------------
@@ -490,6 +527,105 @@ def moe_layer(params: dict, x: jax.Array, spec: MoESpec, act: str):
         check_vma=False,
     )(params["router"], params["w_gate"], params["w_in"], params["w_out"], x)
     return y, aux
+
+
+# ---------------------------------------------------------------------------
+# Dropless MoE over a held share of the experts (DeepSeek-V3 routing)
+# ---------------------------------------------------------------------------
+
+def route_sigmoid(x: jax.Array, router: jax.Array, bias: jax.Array,
+                  spec: MoESpec) -> Tuple[jax.Array, jax.Array]:
+    """Aux-loss-free routing over all `n_experts`: s = sigmoid(x·W_r) in
+    f32, the top k picked by s + bias, weights s over their top-k sum
+    times `routed_scale`; the bias moves the choice only.
+    x (T, d) -> (experts (T, k) int32, weights (T, k) f32)."""
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32),
+                               router.astype(jnp.float32),
+                               precision=jax.lax.Precision.HIGHEST))
+    _, top_e = jax.lax.top_k(s + bias.astype(jnp.float32), spec.top_k)
+    top_w = jnp.take_along_axis(s, top_e, axis=-1)
+    top_w = top_w / (jnp.sum(top_w, axis=-1, keepdims=True) + 1e-20)
+    return top_e.astype(jnp.int32), top_w * spec.routed_scale
+
+
+def _gmm_tiling(m: int, k: int, n: int, itemsize: int) -> Tuple[int, int, int]:
+    """(tm, tk, tn) of the grouped matmul: 128-row tiles (16 rows for a
+    decode step's few pairs), 512-deep contraction tiles, and output tiles
+    as wide as fit 1.5 MB of weights (all of an expert's 1,408 columns
+    behind a 512-deep tile)."""
+    tm = 128 if m >= 128 else -(-m // 16) * 16
+    tk = min(k, 512)
+    tn = n if n * tk * itemsize <= 3 << 19 else 512
+    return tm, tk, tn
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+                   group_offset: int, out_dtype) -> jax.Array:
+    """Rows of `lhs` sorted by group, times their group's matrix, for the
+    groups [group_offset, group_offset + rhs.shape[0]) only: the megablox
+    grouped matmul (`group_sizes` holds every group's row count; rows of
+    other groups come out zero).  lhs (m, k), rhs (G_held, k, n) -> (m, n);
+    m is padded to the row tile here."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    from repro.core.execution import resolve_interpret
+
+    m, k = lhs.shape
+    tiling = _gmm_tiling(m, k, rhs.shape[2], jnp.dtype(rhs.dtype).itemsize)
+    m_pad = -(-m // tiling[0]) * tiling[0]
+    if m_pad != m:
+        lhs = jnp.pad(lhs, ((0, m_pad - m), (0, 0)))
+    out = megablox.gmm(lhs, rhs.astype(lhs.dtype), group_sizes,
+                       jnp.dtype(out_dtype), tiling,
+                       jnp.asarray(group_offset, jnp.int32), None, False,
+                       resolve_interpret())
+    return out[:m]
+
+
+def moe_routed_held(params: dict, x: jax.Array, spec: MoESpec, act: str):
+    """The held experts' part of a dropless expert layer.
+
+    Every token is routed over all `n_experts`; its (token, expert) pairs
+    are sorted by expert and the held experts' rows go through three
+    grouped matmuls (gate, up, down) with per-expert row counts — no
+    capacity, nothing dropped.  Pairs of experts held elsewhere add
+    nothing here.  x (T, d) -> (y (T, d) f32, pairs per held expert
+    (E_held,) int32)."""
+    t, d = x.shape
+    k, e0, eh = spec.top_k, spec.held_offset, spec.e_held
+    top_e, top_w = route_sigmoid(x, params["router"], params["select_bias"],
+                                 spec)
+    flat_e = top_e.reshape(-1)
+    order = jnp.argsort(flat_e, stable=True)                 # (T*k,)
+    sorted_e = flat_e[order]
+    group_sizes = jnp.bincount(flat_e, length=spec.n_experts).astype(jnp.int32)
+    xs = jnp.take(x, order // k, axis=0)
+    gate = grouped_matmul(xs, params["w_gate"], group_sizes, e0, x.dtype)
+    up = grouped_matmul(xs, params["w_in"], group_sizes, e0, x.dtype)
+    ys = grouped_matmul(act_fn(act)(gate) * up, params["w_out"], group_sizes,
+                        e0, x.dtype)
+    held = (sorted_e >= e0) & (sorted_e < e0 + eh)
+    ys = jnp.where(held[:, None], ys, 0)
+    inv = jnp.zeros_like(order).at[order].set(jnp.arange(t * k))
+    y = jnp.einsum("tkd,tk->td", jnp.take(ys, inv, axis=0).reshape(t, k, d),
+                   top_w, preferred_element_type=jnp.float32)
+    return y, group_sizes[e0:e0 + eh]
+
+
+def moe_dropless(params: dict, x: jax.Array, spec: MoESpec, act: str):
+    """x (B, S, d) -> (y, aux): the held experts' part plus the shared
+    experts (which every chip computes alike).  aux carries `pairs`, the
+    (token, expert) pairs computed per held expert."""
+    b, s, d = x.shape
+    xt = x.reshape(b * s, d)
+    y, pairs = moe_routed_held(params, xt, spec, act)
+    if spec.n_shared:
+        y = y + mlp({"w_in": params["shared_in"],
+                     "w_gate": params["shared_gate"],
+                     "w_out": params["shared_out"]}, xt, act)
+    zero = jnp.zeros((), jnp.float32)
+    return y.astype(x.dtype).reshape(b, s, d), {
+        "moe_lb": zero, "moe_z": zero, "pairs": pairs}
 
 
 # ---------------------------------------------------------------------------

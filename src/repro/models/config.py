@@ -1,10 +1,12 @@
 """Unified architecture config covering every assigned family.
 
 One frozen dataclass drives param init, train loss, prefill and decode for
-dense / SWA / GQA transformers, MoE transformers, RWKV-6, Mamba-2 hybrids
+dense / SWA / GQA transformers, MoE transformers, DeepSeek-V3-layout
+transformers (multi-head latent attention, sigmoid-routed experts with
+shared experts, leading dense layers; Moonlight), RWKV-6, Mamba-2 hybrids
 (Zamba-2), encoder-only (HuBERT) and VLM (InternVL2) backbones.  Family-
 specific knobs are optional blocks; `configs/<arch>.py` instantiates the
-exact assigned values and a reduced `smoke()` variant of the same family.
+exact assigned values and a reduced `SMOKE` variant of the same family.
 """
 
 from __future__ import annotations
@@ -16,11 +18,55 @@ from typing import Optional, Tuple
 
 @dataclasses.dataclass(frozen=True)
 class MoESpec:
+    """Token-choice top-k experts.
+
+    `scoring` "softmax" (the default) routes through softmax probabilities
+    with a capacity per expert; "sigmoid" is DeepSeek-V3's aux-loss-free
+    router: per-expert sigmoid scores, the top k picked by score plus a
+    learned selection bias (which does not enter the weights), weights
+    normalised over the top k and scaled by `routed_scale`.
+    The sigmoid layer is dropless.  `n_shared` shared experts (one gated
+    MLP of width n_shared * d_ff_expert) see every token.
+
+    `n_held` / `held_offset`: this chip holds experts [held_offset,
+    held_offset + n_held) of a layer divided by expert parallelism (0:
+    all).  It routes over all `n_experts` and computes its own experts'
+    part of the result; the absent experts' part is another chip's.
+    """
     n_experts: int
     top_k: int
     d_ff_expert: int
     capacity_factor: float = 1.25
     router_z_coef: float = 1e-3
+    scoring: str = "softmax"         # softmax | sigmoid
+    routed_scale: float = 1.0
+    n_shared: int = 0
+    n_held: int = 0
+    held_offset: int = 0
+
+    @property
+    def e_held(self) -> int:
+        return self.n_held or self.n_experts
+
+    @property
+    def dropless(self) -> bool:
+        return self.scoring == "sigmoid"
+
+
+@dataclasses.dataclass(frozen=True)
+class MLASpec:
+    """Multi-head latent attention (DeepSeek-V2 §2.1): queries projected
+    directly (no q low-rank), keys and values from a `kv_lora_rank` latent
+    plus one shared `qk_rope_head_dim` rotary key.  The cache holds the
+    latent and the rotary key only."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,6 +121,8 @@ class ArchConfig:
     causal: bool = True              # False => encoder-only (no decode path)
     # blocks
     moe: Optional[MoESpec] = None
+    mla: Optional[MLASpec] = None
+    first_dense: int = 0             # leading layers with a dense MLP of d_ff
     ssm: Optional[SSMSpec] = None
     hybrid: Optional[HybridSpec] = None
     # modality frontend stub ([audio]/[vlm]): precomputed embeddings enter
@@ -127,19 +175,35 @@ class ArchConfig:
     # ---- parameter count (for 6ND model-flops accounting) ----
     def param_count(self, active_only: bool = False) -> int:
         d, l, v = self.d_model, self.n_layers, self.padded_vocab
-        total = v * d  # embed
+        total = v * d + d  # embed, final norm
         if not self.tie_embeddings:
             total += v * d
         if self.family == "transformer":
             dh, hq, hkv = self.dh, self.n_heads, self.n_kv_heads
-            attn = d * hq * dh + 2 * d * hkv * dh + hq * dh * d
-            if self.moe:
-                e = self.moe.top_k if active_only else self.moe.n_experts
-                ffn = d * self.moe.n_experts  # router (always dense)
-                ffn += e * (3 * d * self.moe.d_ff_expert)
+            if self.mla:
+                m = self.mla
+                attn = (d * hq * m.qk_head_dim
+                        + d * (m.kv_lora_rank + m.qk_rope_head_dim)
+                        + m.kv_lora_rank
+                        + m.kv_lora_rank * hq * (m.qk_nope_head_dim + m.v_head_dim)
+                        + hq * m.v_head_dim * d)
             else:
-                ffn = 3 * d * self.d_ff
-            total += l * (attn + ffn + 2 * d)
+                attn = d * hq * dh + 2 * d * hkv * dh + hq * dh * d
+            dense_ffn = (3 if self.gated_mlp else 2) * d * self.d_ff
+            n_dense = self.first_dense if self.moe else l
+            total += n_dense * (attn + dense_ffn + 2 * d)
+            if self.moe:
+                mo = self.moe
+                per_expert = 3 * d * mo.d_ff_expert
+                # a held share: the routed experts held here; active: the
+                # expected pairs per token on this chip, top_k * held / E
+                e = (mo.top_k * mo.e_held / mo.n_experts if active_only
+                     else mo.e_held)
+                ffn = d * mo.n_experts        # router (always dense)
+                if mo.dropless:
+                    ffn += mo.n_experts       # selection bias
+                ffn += e * per_expert + mo.n_shared * per_expert
+                total += (l - n_dense) * (attn + ffn + 2 * d)
         elif self.family == "rwkv6":
             di = d
             tm = 6 * d * di + di * d + 64 * d * 10  # r,k,v,g,w,o + lora-ish decay

@@ -92,3 +92,27 @@ def test_ternary_matmul_compiles_at_paper_widths(one_chip):
         _spec((1024, 32), F32, one_chip), _spec((16, 32), I8, one_chip),
         scale=0.5, interpret=False)
     _assert_mosaic(lowered)
+
+
+# the held experts' grouped matmul at Moonlight-16B-A3B's widths: rows of
+# (token, expert) pairs, 8 of 64 experts held, gate/up 2048 -> 1408 and
+# down 1408 -> 2048; an 8,128-token prefill and a one-token decode step
+GMM_SHAPES = {
+    "prefill_up": (8128 * 6, 2048, 1408),
+    "prefill_down": (8128 * 6, 1408, 2048),
+    "decode_up": (6, 2048, 1408),
+    "decode_down": (6, 1408, 2048),
+}
+
+
+@pytest.mark.parametrize("name", sorted(GMM_SHAPES))
+def test_held_expert_grouped_matmul_compiles(one_chip, name, monkeypatch):
+    from repro.core import execution
+    from repro.models.blocks import grouped_matmul
+
+    monkeypatch.setattr(execution, "_probe_interpret", lambda: False)
+    rows, k, n = GMM_SHAPES[name]
+    lowered = jax.jit(lambda x, w, g: grouped_matmul(x, w, g, 0, BF16)).lower(
+        _spec((rows, k), BF16, one_chip), _spec((8, k, n), BF16, one_chip),
+        _spec((64,), jnp.int32, one_chip))
+    _assert_mosaic(lowered)
