@@ -141,13 +141,38 @@ def _stacks(params: PyTree):
     return [params[k] for k in ("dense_layers", "layers") if k in params]
 
 
-def _to_compute(tree: PyTree, cdt) -> PyTree:
+def _casts(kp, a, cdt, lead: int = 0) -> bool:
+    """Whether `_to_compute` casts this leaf: a float32 matrix (past `lead`
+    stacked-layer axes) other than the router, and a compute dtype that
+    differs."""
+    return (a.dtype == jnp.float32 and jnp.dtype(cdt) != jnp.float32
+            and a.ndim - lead >= 2
+            and not jax.tree_util.keystr(kp).endswith("['router']"))
+
+
+def _to_compute(tree: PyTree, cdt, lead: int = 0) -> PyTree:
     """Float32 matrices to the compute dtype; the router stays float32."""
-    def leaf(kp, a):
-        if jax.tree_util.keystr(kp).endswith("['router']"):
-            return a
-        return a.astype(cdt) if a.dtype == jnp.float32 and a.ndim >= 2 else a
-    return jax.tree_util.tree_map_with_path(leaf, tree)
+    return jax.tree_util.tree_map_with_path(
+        lambda kp, a: a.astype(cdt) if _casts(kp, a, cdt, lead) else a, tree)
+
+
+def _lead(key: str) -> int:
+    """Stacked-layer axes of a top-level params entry."""
+    return int(key in ("dense_layers", "layers"))
+
+
+def casts_any(params: PyTree, cdt) -> bool:
+    """Whether `compute_params` would change the dtype of any leaf."""
+    return any(_casts(kp, a, cdt, _lead(kp[0].key)) for kp, a in
+               jax.tree_util.tree_flatten_with_path(params)[0])
+
+
+def compute_params(params: PyTree, cdt) -> PyTree:
+    """The params as prefill and decode compute with them: each layer's
+    matrices cast as `_to_compute` casts them inside the steps, and the
+    embedding, head and front-end projection as the steps cast them, so a
+    step on the copy rounds the same values to the same operands."""
+    return {k: _to_compute(v, cdt, _lead(k)) for k, v in params.items()}
 
 
 # ---------------------------------------------------------------------------

@@ -165,6 +165,8 @@ class DRService:
         self.registry = registry if registry is not None else ModelRegistry()
         self.slo = SLOTracker(clock=self.clock)
         self.cache = BoundedCompileCache(compile_cache_size, tracker=self.slo)
+        # compute-dtype copies of LM params trees, made once per tree
+        self.lm_params = serve_step.ComputeParamsCache(tracker=self.slo)
         self.batcher = MicroBatcher(max_queue=max_queue)
         self.update_fraction = update_fraction
         # train-while-serve bookkeeping (per model name).  All three dicts
@@ -509,34 +511,41 @@ class DRService:
 
     # ---- LM steps through the same queue ------------------------------------
     # The *_step builders are the single source of truth for how an LM step
-    # is constructed (cache key, rows derivation, donation contract); both
-    # the direct lm_* methods and the DeadlineScheduler's LM helpers call
-    # them, so the two admission paths can't drift apart.
+    # is constructed (params copy, cache key, rows derivation, donation
+    # contract); both the direct lm_* methods and the DeadlineScheduler's
+    # LM helpers call them, so the two admission paths can't drift apart.
+    # Each returns the params the program takes: the compute-dtype copy of
+    # the caller's tree (`serve_step.ComputeParamsCache`), or the caller's
+    # tree itself where it needs no cast.
     def prefill_step(self, cfg: Any, mesh: Mesh, params: PyTree,
                      batch: PyTree, cache_size: int,
-                     ) -> Tuple[Callable[..., Any], int]:
-        """(jitted prefill, batch rows) — the jit comes from THIS service's
-        bounded compile cache, shared with the DR bucket programs."""
+                     ) -> Tuple[Callable[..., Any], PyTree, int]:
+        """(jitted prefill, its params, batch rows) — the jit comes from
+        THIS service's bounded compile cache, shared with the DR bucket
+        programs."""
+        params = self.lm_params.get(cfg, params)
         fn = serve_step.make_prefill(cfg, mesh, params, batch, cache_size,
                                      cache=self.cache)
         rows = jax.tree.leaves(batch)[0].shape[0]
-        return fn, int(rows)
+        return fn, params, int(rows)
 
     def decode_step(self, cfg: Any, mesh: Mesh, params: PyTree,
                     token: jax.Array, kv_cache: PyTree,
-                    ) -> Tuple[Callable[..., Any], int]:
-        """(jitted decode, batch rows); the kv cache is donated — don't
-        reuse the argument after the step runs."""
+                    ) -> Tuple[Callable[..., Any], PyTree, int]:
+        """(jitted decode, its params, batch rows); the kv cache is donated
+        — don't reuse the argument after the step runs."""
+        params = self.lm_params.get(cfg, params)
         fn = serve_step.make_decode(cfg, mesh, params, kv_cache,
                                     cache=self.cache)
-        return fn, int(token.shape[0])
+        return fn, params, int(token.shape[0])
 
     def lm_prefill(self, cfg: Any, mesh: Mesh, params: PyTree, batch: PyTree,
                    cache_size: int, *, tag: Hashable = "lm",
                    max_delay_ms: Optional[float] = None) -> Ticket:
         """Admit one LM prefill through the queue; resolves with
         `(logits, kv_cache)`."""
-        fn, rows = self.prefill_step(cfg, mesh, params, batch, cache_size)
+        fn, params, rows = self.prefill_step(cfg, mesh, params, batch,
+                                             cache_size)
         return self.submit_step(tag, "prefill", fn, params, batch,
                                 rows=rows, max_delay_ms=max_delay_ms)
 
@@ -545,7 +554,8 @@ class DRService:
                   max_delay_ms: Optional[float] = None) -> Ticket:
         """Admit one LM decode step through the queue (same contract as
         `lm_prefill`)."""
-        fn, rows = self.decode_step(cfg, mesh, params, token, kv_cache)
+        fn, params, rows = self.decode_step(cfg, mesh, params, token,
+                                            kv_cache)
         return self.submit_step(tag, "decode", fn, params, token, kv_cache,
                                 rows=rows, max_delay_ms=max_delay_ms)
 

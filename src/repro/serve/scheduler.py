@@ -118,16 +118,16 @@ class DeadlineScheduler:
     def lm_prefill(self, cfg: Any, mesh: Any, params: Any, batch: Any,
                    cache_size: int, *, tag: Hashable = "lm",
                    max_delay_ms: Optional[float] = None):
-        fn, rows = self.service.prefill_step(cfg, mesh, params, batch,
-                                             cache_size)
+        fn, params, rows = self.service.prefill_step(cfg, mesh, params,
+                                                     batch, cache_size)
         return self.submit_step(tag, "prefill", fn, params, batch,
                                 rows=rows, max_delay_ms=max_delay_ms)
 
     def lm_decode(self, cfg: Any, mesh: Any, params: Any, token: Any,
                   kv_cache: Any, *, tag: Hashable = "lm",
                   max_delay_ms: Optional[float] = None):
-        fn, rows = self.service.decode_step(cfg, mesh, params, token,
-                                            kv_cache)
+        fn, params, rows = self.service.decode_step(cfg, mesh, params,
+                                                    token, kv_cache)
         return self.submit_step(tag, "decode", fn, params, token, kv_cache,
                                 rows=rows, max_delay_ms=max_delay_ms)
 

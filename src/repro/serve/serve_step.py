@@ -8,19 +8,26 @@ cache (`repro.serve.batching.BoundedCompileCache`): per (config, mesh,
 shape-signature) the jit is built once and LRU-evicted under pressure, so
 a long-lived server cycling through configs/meshes doesn't pin every
 executable it ever compiled.
+
+The programs take the params as they compute with them
+(`compute_params`): a tree stored in float32 is cast to the compute dtype
+once (`ComputeParamsCache`), not inside every step.
 """
 
 from __future__ import annotations
 
+import collections
+import threading
 from typing import Any
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import config_hash
 from repro.dist import sharding as shard_rules
 from repro.launch.mesh import require_auto_axes
-from repro.models import api
+from repro.models import api, transformer
 from repro.models.config import ArchConfig
 from repro.serve.batching import BoundedCompileCache
 
@@ -39,6 +46,64 @@ def _tree_sig(tree: PyTree):
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)
     return tuple((jax.tree_util.keystr(kp), tuple(leaf.shape), str(leaf.dtype))
                  for kp, leaf in flat)
+
+
+def _casts_any(cfg: ArchConfig, params: PyTree) -> bool:
+    return cfg.family == "transformer" and transformer.casts_any(
+        params, jnp.dtype(cfg.compute_dtype))
+
+
+def compute_params(cfg: ArchConfig, params: PyTree) -> PyTree:
+    """`params` with each float32 matrix the steps would cast in the compute
+    dtype (`transformer.compute_params`: the router and vectors keep their
+    dtype), so prefill and decode compute on the same operands without a
+    cast of their own.  `params` itself where no leaf would change dtype:
+    a tree stored in the compute dtype, or a family that casts in its own
+    steps."""
+    if not _casts_any(cfg, params):
+        return params
+    return transformer.compute_params(params, jnp.dtype(cfg.compute_dtype))
+
+
+class ComputeParamsCache:
+    """`compute_params`, made once per params tree: an LRU of `MAXSIZE`
+    copies keyed on the identities of the tree's leaves.  Each entry holds
+    its source leaves, so no other array can take their ids while it
+    lives; a new params version has new leaves and gets its own copy, and
+    old versions age out.  A tree that needs no cast passes through
+    uncached.  Each copy is timed as the `tracker`'s (an `slo.SLOTracker`)
+    `lm.params_cast` stage."""
+
+    MAXSIZE = 2
+
+    def __init__(self, tracker: Any):
+        self._tracker = tracker
+        self._lock = threading.Lock()
+        self._d: "collections.OrderedDict" = collections.OrderedDict()  # guarded-by: _lock
+
+    def __len__(self) -> int:
+        with self._lock:
+            return len(self._d)
+
+    def get(self, cfg: ArchConfig, params: PyTree) -> PyTree:
+        leaves, treedef = jax.tree.flatten(params)
+        key = (cfg.family, cfg.compute_dtype, treedef, tuple(map(id, leaves)))
+        with self._lock:
+            hit = self._d.get(key)
+            if hit is not None:
+                self._d.move_to_end(key)
+                return hit[1]
+        if not _casts_any(cfg, params):
+            return params
+        with self._tracker.span("lm.params_cast"):
+            copy = jax.block_until_ready(compute_params(cfg, params))
+        with self._lock:
+            # a racing thread's copy of the same tree wins; ours is dropped
+            copy = self._d.setdefault(key, (leaves, copy))[1]
+            self._d.move_to_end(key)
+            while len(self._d) > self.MAXSIZE:
+                self._d.popitem(last=False)
+        return copy
 
 
 def make_prefill(cfg: ArchConfig, mesh: Mesh, params_like: PyTree,
